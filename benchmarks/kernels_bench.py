@@ -141,7 +141,7 @@ def _session(backend, N, M, dblk, mesh=None, data=None):
 def _abstract_mesh():
     """Shape-only (data, model) mesh — per-shard costing needs no devices."""
     from jax.sharding import AbstractMesh
-    return AbstractMesh((("data", MESH_SHAPE[0]), ("model", MESH_SHAPE[1])))
+    return AbstractMesh(MESH_SHAPE, ("data", "model"))
 
 
 def _tree_spec(backend, N, M, dblk, mesh=None, concrete=False):
@@ -471,4 +471,6 @@ if __name__ == "__main__":
                     help="also run numeric parity/NaN checks and fail on "
                          "regression vs benchmarks/kernels_baseline.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(smoke=args.smoke)

@@ -505,6 +505,8 @@ if __name__ == "__main__":
                          "(zipf block selection), heavy_tail (Pareto "
                          "stragglers)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.scenario is not None:
         if not SCENARIOS[args.scenario](print, smoke=args.smoke):
             raise SystemExit(1)

@@ -42,7 +42,7 @@ def main():
     # --- kernel cross-check: fused Pallas gradient == autodiff gradient ---
     X0, y0 = jnp.asarray(data.X[0]), jnp.asarray(data.y[0])
     w = jnp.zeros(args.dim)
-    g_kernel = ops.logreg_grad(X0, y0, w, interpret=True)
+    g_kernel = ops.logreg_grad(X0, y0, w)
     g_auto = jax.grad(lambda z: loss_fn(z, (X0, y0)))(w)
     print(f"pallas logreg_grad vs autodiff: max|Δ| = "
           f"{float(jnp.max(jnp.abs(g_kernel - g_auto))):.2e}")

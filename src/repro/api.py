@@ -97,6 +97,12 @@ class ConsensusSession:
             l2_coef=l2_coef, rho_scale=rho_scale)
         spec = problem.spec(cfg, selector=selector, delay_model=delay_model,
                             backend=backend, mesh=mesh, autotune=autotune)
+        if isinstance(spec.space.mesh, jax.sharding.Mesh):
+            # the fixed data goes to the devices whose workers read it,
+            # once — not resharded from one device every epoch
+            from .core.sharded import consensus_data_shardings
+            problem = dataclasses.replace(problem, data=jax.device_put(
+                problem.data, consensus_data_shardings(spec, problem.data)))
         return ConsensusSession(spec=spec, cfg=cfg, data=problem.data,
                                 problem=problem)
 

@@ -47,7 +47,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..launch.mesh import data_axes, model_axis_size, num_workers
@@ -149,6 +148,14 @@ def consensus_state_shardings(spec: ConsensusSpec, state) -> ConsensusState:
     mesh = spec.space.mesh
     return jax.tree.map(lambda s: NamedSharding(mesh, s),
                         consensus_state_specs(spec, state),
+                        is_leaf=lambda v: isinstance(v, P))
+
+
+def consensus_data_shardings(spec: ConsensusSpec, data):
+    """NamedSharding tree for ``jax.device_put`` of per-worker data."""
+    mesh = spec.space.mesh
+    return jax.tree.map(lambda s: NamedSharding(mesh, s),
+                        consensus_data_specs(spec, data),
                         is_leaf=lambda v: isinstance(v, P))
 
 
@@ -378,8 +385,8 @@ def sharded_epoch(spec: ConsensusSpec, state: ConsensusState, data
     sspecs = consensus_state_specs(spec, state)
     in_specs = (sspecs, consensus_data_specs(spec, data), P(), P())
     out_specs = (sspecs, {"loss": P(), "selected_fraction": P()})
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(state, data, spec.edge, spec.rho_vec)
 
 

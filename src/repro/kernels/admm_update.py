@@ -16,8 +16,8 @@ Two entry points:
   (N, M, dblk) grid that additionally fuses Algorithm 1's sel-masked
   select writes for y / w_cache / x. One pass over the worker bundles
   instead of four (update + three ``jnp.where`` merges), with a
-  per-worker rho column (N, 1) so heterogeneous rho_i (the paper's
-  general form) is native.
+  per-worker rho vector (N,) in SMEM so heterogeneous rho_i (the
+  paper's general form) is native.
 """
 from __future__ import annotations
 
@@ -27,24 +27,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import LANE, pick_blk_m, pick_lane_tile, resolve_interpret
 
 BLK_R = 256
-BLK_M = 8
-LANE = 128
-
-
-def pick_blk_m(M: int, tuned: Optional[int] = None) -> int:
-    """Sublane grid tile: the largest divisor of M that is <= BLK_M (the
-    M grid is never padded — block j is row j everywhere, the block-id
-    contract — so M=1 PS commits and odd model-shard sizes tile at a
-    smaller divisor). A cached autotuner winner ``tuned`` is used
-    verbatim when it divides M."""
-    if tuned is not None and 0 < tuned <= M and M % tuned == 0:
-        return tuned
-    bm = min(M, BLK_M)
-    while M % bm:
-        bm -= 1
-    return bm
+BLK_D = 2048          # lane tile cap of the (N, M, dblk) worker grid
 
 
 # ---------------------------------------------------------------------------
@@ -64,9 +52,11 @@ def _kernel_2d(rho_ref, g_ref, y_ref, zt_ref, x_ref, ynew_ref, w_ref):
     w_ref[...] = w.astype(w_ref.dtype)
 
 
-def admm_worker_update_2d(g, y, z_tilde, rho, *, interpret: bool = True):
+def admm_worker_update_2d(g, y, z_tilde, rho, *,
+                          interpret: Optional[bool] = None):
     """g, y, z_tilde: (R, 128)-aligned 2D arrays; rho: (1, 1) array —
-    a traced operand, NOT a compile-time constant. Returns (x, y_new, w)."""
+    a traced operand, NOT a compile-time constant. Returns (x, y_new, w).
+    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
     R, C = g.shape
     assert C % LANE == 0 and R % 8 == 0, (R, C)
     blk_r = min(BLK_R, R)
@@ -80,7 +70,7 @@ def admm_worker_update_2d(g, y, z_tilde, rho, *, interpret: bool = True):
         in_specs=[rho_spec, spec, spec, spec],
         out_specs=[spec, spec, spec],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rho, g, y, z_tilde)
 
 
@@ -94,7 +84,7 @@ def _kernel_3d(rho_ref, m_ref, g_ref, y_ref, zt_ref, w_ref, *refs,
         x_ref, yo_ref, wo_ref, xo_ref = refs
     else:
         yo_ref, wo_ref = refs
-    rho = rho_ref[0, 0]
+    rho = rho_ref[pl.program_id(0)]            # this worker's rho_i (SMEM)
     keep = m_ref[0] > 0.0                     # (blk_m, 1) — broadcasts
     g = g_ref[0]
     y = y_ref[0]
@@ -108,43 +98,20 @@ def _kernel_3d(rho_ref, m_ref, g_ref, y_ref, zt_ref, w_ref, *refs,
         xo_ref[0] = jnp.where(keep, x, x_ref[0]).astype(xo_ref.dtype)
 
 
-def _pick_lane_tile(d: int, tuned: Optional[int] = None) -> int:
-    """Lane grid tile: the largest lane-multiple <= 2048 dividing d.
-
-    Precondition: ``d % 128 == 0``. Lane-aligned layouts
-    (core.blocks.make_flat_blocks / make_block_layout) guarantee it;
-    raw ragged widths raise an actionable error instead of the old
-    silent non-termination of the decrement loop. A cached autotuner
-    winner ``tuned`` (kernels/autotune.py) is used verbatim when it is
-    a lane multiple dividing d.
-    """
-    if d % LANE != 0:
-        raise ValueError(
-            f"lane tile requires d % {LANE} == 0, got d={d}; build the "
-            f"block table through a lane-aligned layout "
-            f"(core.blocks.make_flat_blocks / make_block_layout round "
-            f"block_dim up to {LANE}) instead of passing ragged rows.")
-    if tuned is not None and tuned % LANE == 0 and 0 < tuned <= d \
-            and d % tuned == 0:
-        return tuned
-    blk_d = min(d, 2048)
-    while d % blk_d:
-        blk_d -= LANE
-    return blk_d
-
-
 def admm_worker_select_update_3d(g, y, z_tilde, w_old, sel_mask, rho,
-                                 x_old=None, *, interpret: bool = True,
+                                 x_old=None, *,
+                                 interpret: Optional[bool] = None,
                                  blk_m: Optional[int] = None,
                                  blk_d: Optional[int] = None):
     """Fused worker update + Alg. 1 select writes, epoch-native.
 
     g, y, z_tilde, w_old [, x_old] : (N, M, d) with d % 128 == 0
-        (lane-aligned layout rows); the M grid tiles at the largest
-        divisor of M <= 8 — never padded;
+        (lane-aligned layout rows); the M grid tiles at 8 rows when 8
+        divides M, else at M — never padded;
     sel_mask : (N, M, 1) float — 1.0 where the (worker, block) pair was
         selected this epoch, 0.0 otherwise;
-    rho      : (N, 1) per-worker penalties (traced operand);
+    rho      : (N,) per-worker penalties (traced operand), held whole in
+        SMEM and read at the worker grid index;
     blk_m, blk_d : optional tile overrides (autotuner winners; validated
         against the divisibility rules, heuristic fallback otherwise).
 
@@ -153,11 +120,11 @@ def admm_worker_select_update_3d(g, y, z_tilde, w_old, sel_mask, rho,
     """
     N, M, d = g.shape
     blk_m = pick_blk_m(M, tuned=blk_m)
-    blk_d = _pick_lane_tile(d, tuned=blk_d)
+    blk_d = pick_lane_tile(d, BLK_D, tuned=blk_d, rows=blk_m)
     grid = (N, M // blk_m, d // blk_d)
     tspec = pl.BlockSpec((1, blk_m, blk_d), lambda n, i, j: (n, i, j))
     mspec = pl.BlockSpec((1, blk_m, 1), lambda n, i, j: (n, i, 0))
-    rspec = pl.BlockSpec((1, 1), lambda n, i, j: (n, 0))
+    rspec = pl.BlockSpec(memory_space=pltpu.SMEM)
     with_x = x_old is not None
     n_out = 3 if with_x else 2
     operands = [rho, sel_mask, g, y, z_tilde, w_old]
@@ -171,5 +138,5 @@ def admm_worker_select_update_3d(g, y, z_tilde, w_old, sel_mask, rho,
         in_specs=in_specs,
         out_specs=[tspec] * n_out,
         out_shape=[jax.ShapeDtypeStruct(g.shape, g.dtype)] * n_out,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)

@@ -50,8 +50,8 @@ import numpy as np
 
 from . import admm_update as _admm
 from . import prox_update as _prox
+from .tiling import LANE, SUBLANE
 
-LANE = 128
 OPS = ("worker_select_update", "server_prox_fused")
 MODES = ("off", "cached", "sweep")
 
@@ -86,13 +86,10 @@ def default_table_path() -> pathlib.Path:
 
 
 def device_kind() -> str:
-    """Normalized device kind of the default backend ("cpu" in interpret
-    containers, e.g. "TPU_v4" on hardware)."""
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = "cpu"
-    return str(kind).strip().replace(" ", "_")
+    """Normalized device kind of the default backend ("cpu" on the CPU
+    backend, e.g. "TPU_v5_lite" on a v5e). A backend that cannot be read
+    raises: its tiles must never be filed under another device's key."""
+    return str(jax.devices()[0].device_kind).strip().replace(" ", "_")
 
 
 def table_key(dev: str, op: str, N: int, M: int, d: int,
@@ -165,8 +162,9 @@ def lookup_tile(op: str, N: int, M: int, d: int,
     cfg = lookup(op, N, M, d, dtype)
     if cfg is None:
         return None
-    if M % cfg.blk_m or d % cfg.blk_d or cfg.blk_d % LANE:
-        return None                       # stale entry for another shape
+    if M % cfg.blk_m or (cfg.blk_m % SUBLANE and cfg.blk_m != M) \
+            or d % cfg.blk_d or cfg.blk_d % LANE:
+        return None                       # stale or chip-refused entry
     return cfg.blk_m, cfg.blk_d
 
 
@@ -175,13 +173,15 @@ def lookup_tile(op: str, N: int, M: int, d: int,
 # ---------------------------------------------------------------------------
 
 def tile_candidates(op: str, N: int, M: int, d: int) -> List[Tuple[int, int]]:
-    """Feasible (blk_m, blk_d) grid tiles: blk_m a divisor of M (the M
-    grid is never padded — block-id contract), blk_d a lane multiple
-    dividing d, double-buffered VMEM residency under budget."""
+    """Feasible (blk_m, blk_d) grid tiles: blk_m a multiple of 8
+    dividing M, or M itself when 8 does not divide it (the M grid is
+    never padded — block-id contract — and Mosaic refuses any other
+    sublane tile), blk_d a lane multiple dividing d, double-buffered
+    VMEM residency under budget."""
     if d % LANE != 0:
         raise ValueError(f"autotune sweep requires lane-aligned d "
                          f"(d % {LANE} == 0), got d={d}")
-    blk_ms = [bm for bm in (1, 2, 4, 8, 16) if bm <= M and M % bm == 0]
+    blk_ms = [bm for bm in (SUBLANE, 2 * SUBLANE) if M % bm == 0] or [M]
     blk_ds = [bd for bd in (LANE, 256, 512, 1024, 2048, 4096, 8192)
               if bd <= d and d % bd == 0]
     if d <= 8192 and d not in blk_ds:
@@ -226,7 +226,7 @@ def _op_inputs(op: str, N: int, M: int, d: int):
     if op == "worker_select_update":
         return (t(0), t(1), t(2), t(3),
                 jnp.ones((N, M, 1), jnp.float32),
-                jnp.full((N, 1), 2.0, jnp.float32))
+                jnp.full((N,), 2.0, jnp.float32))
     return (t(0)[0], t(1), jnp.ones((N, M, 1), jnp.float32),
             jnp.full((M, 1), 6.0, jnp.float32))
 
@@ -339,7 +339,7 @@ def _smoke(shapes: List[Tuple[int, int, int]]) -> int:
         bm, bd = int(e["blk_m"]), int(e["blk_d"])
         if op not in OPS:
             raise SystemExit(f"[autotune] unknown op in key {key!r}")
-        if M % bm or d % bd or bd % LANE:
+        if M % bm or (bm % SUBLANE and bm != M) or d % bd or bd % LANE:
             raise SystemExit(f"[autotune] invalid tile {bm}x{bd} for {key}")
         if 2 * _TILES_PER_STEP[op] * bm * bd * 4 > VMEM_BUDGET:
             raise SystemExit(f"[autotune] tile {bm}x{bd} over VMEM budget "

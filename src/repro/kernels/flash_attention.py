@@ -16,11 +16,14 @@ the assigned archs).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import resolve_interpret
 
 DEFAULT_BLOCK = 128
 NEG_INF = -1e30
@@ -66,8 +69,9 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
                          block_q: int = DEFAULT_BLOCK,
                          block_k: int = DEFAULT_BLOCK,
                          scale: float = None,
-                         interpret: bool = True):
-    """q: (BH, S, hd); k, v: (BH, T, hd); S % block_q == T % block_k == 0."""
+                         interpret: Optional[bool] = None):
+    """q: (BH, S, hd); k, v: (BH, T, hd); S % block_q == T % block_k == 0.
+    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
     BH, S, hd = q.shape
     T = k.shape[1]
     bq, bk = min(block_q, S), min(block_k, T)
@@ -91,5 +95,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),     # running denom l
             pltpu.VMEM((bq, hd), jnp.float32),    # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

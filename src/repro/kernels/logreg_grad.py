@@ -20,11 +20,14 @@ formulation, not a workaround.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import resolve_interpret
 
 BLK = 128
 
@@ -48,7 +51,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def matmul(a, b, *, transpose_a: bool = False, interpret: bool = True,
+def matmul(a, b, *, transpose_a: bool = False,
+           interpret: Optional[bool] = None,
            blk_m: int = BLK, blk_n: int = BLK, blk_k: int = BLK):
     """C = A^T B if transpose_a else A B.  All dims must be tile-aligned
     (ops.py pads)."""
@@ -74,7 +78,7 @@ def matmul(a, b, *, transpose_a: bool = False, interpret: bool = True,
         out_specs=pl.BlockSpec((blk_m, blk_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((blk_m, blk_n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)
 
 
@@ -84,7 +88,7 @@ def _margin_kernel(s_ref, y_ref, v_ref):
     v_ref[...] = (-y * jax.nn.sigmoid(-y * s)).astype(v_ref.dtype)
 
 
-def margin(s, y, *, interpret: bool = True):
+def margin(s, y, *, interpret: Optional[bool] = None):
     """s, y: (m, C) tile-aligned. v = -y*sigmoid(-y*s)."""
     M, C = s.shape
     blk_m = min(256, M)
@@ -96,5 +100,5 @@ def margin(s, y, *, interpret: bool = True):
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(s.shape, s.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(s, y)

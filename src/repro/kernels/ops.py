@@ -7,10 +7,10 @@ row up to the 128-lane boundary at layout-build time, so these wrappers
 pad-copy branches burned an extra HBM round trip per epoch on ragged
 layouts). Unaligned buffers raise an actionable error pointing at the
 layout constructors. The MXU ops (``matmul`` / ``logreg_grad``) still
-pad internally — data matrices are not layout-controlled. ``interpret``
-defaults to True off-TPU (this container is CPU-only: interpret mode
-executes the kernel body in Python for validation; on TPU the same code
-compiles to Mosaic).
+pad internally — data matrices are not layout-controlled.
+``interpret=None`` (the default) compiles the kernels with Mosaic on a
+TPU and runs them in the Pallas interpreter on any other backend
+(``kernels.tiling.default_interpret``); tests pass it explicitly.
 
 Tile shapes (``blk_m``, ``blk_d``) default to the static heuristics in
 ``admm_update.py`` / ``prox_update.py``; the fused epoch ops accept a
@@ -43,13 +43,7 @@ from . import admm_update as _admm
 from . import logreg_grad as _lg
 from . import prox_update as _prox
 from . import ref as _ref
-
-LANE = 128
-SUBLANE = 8
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from .tiling import LANE, SUBLANE
 
 
 def _round_up(n: int, m: int) -> int:
@@ -88,7 +82,6 @@ def admm_worker_update(g, y, z_tilde, rho,
     """Fused eqs. (11)+(12)+(9) on arbitrarily-shaped buffers. ``rho`` is
     a traced operand (python float or 0-d array) — distinct rho values
     share one compilation."""
-    interpret = _default_interpret() if interpret is None else interpret
     g2, orig = _to_2d(g)
     y2, _ = _to_2d(y)
     z2, _ = _to_2d(z_tilde)
@@ -111,7 +104,6 @@ def prox_consensus(z_tilde, w_sum, rho_sum, gamma: float, l1: float = 0.0,
                    tile: Optional[Tuple[int, int]] = None):
     """Fused eq. (13). z_tilde, w_sum: (M, d) lane-aligned; rho_sum: (M,)
     or (M, 1). ``tile=(blk_m, blk_d)`` statically overrides the grid."""
-    interpret = _default_interpret() if interpret is None else interpret
     M, d = z_tilde.shape
     rho_sum = rho_sum.reshape(M, 1).astype(z_tilde.dtype)
     if boundary_stub:
@@ -163,10 +155,9 @@ def admm_worker_select_update(g, y, z_tilde, w_old, sel, rho_vec,
 
     Returns (y', w'[, x']).
     """
-    interpret = _default_interpret() if interpret is None else interpret
     N, M, d = g.shape
     smask = sel.astype(g.dtype)[..., None]
-    rho2 = rho_vec.astype(jnp.float32).reshape(N, 1)
+    rho2 = rho_vec.astype(jnp.float32).reshape(N)
     if boundary_stub:
         shapes = [jax.ShapeDtypeStruct(g.shape, g.dtype)] * (
             2 if x_old is None else 3)
@@ -209,7 +200,6 @@ def server_prox_update(z_cur, w_cache, edge, rho_sum, gamma: float,
     rho_sum: (M,) traced per-block penalty sums; ``tile=(blk_m, blk_d)``
     statically overrides the grid. Returns z_new (M, d).
     """
-    interpret = _default_interpret() if interpret is None else interpret
     N, M, d = w_cache.shape
     emask = edge.astype(z_cur.dtype)[..., None]
     rs = rho_sum.astype(jnp.float32).reshape(M, 1)
@@ -240,7 +230,6 @@ def _pad2(a, rm, cm):
 @functools.partial(jax.jit, static_argnames=("transpose_a", "interpret"))
 def matmul(a, b, transpose_a: bool = False,
            interpret: Optional[bool] = None):
-    interpret = _default_interpret() if interpret is None else interpret
     if transpose_a:
         K, M = a.shape
     else:
@@ -256,7 +245,6 @@ def matmul(a, b, transpose_a: bool = False,
 def logreg_grad(X, y, w, interpret: Optional[bool] = None):
     """Gradient of mean logistic loss: X (m, d), y (m,) in {-1,+1},
     w (d,). Composition of three kernels; X^T never materialized."""
-    interpret = _default_interpret() if interpret is None else interpret
     m, d = X.shape
     Xp = _pad2(X, _lg.BLK, _lg.BLK)
     mp, dp = Xp.shape

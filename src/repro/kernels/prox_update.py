@@ -23,10 +23,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .admm_update import pick_blk_m
+from .tiling import pick_blk_m, pick_lane_tile, resolve_interpret
 
-BLK_M = 8
-LANE = 128
+BLK_D = 512           # lane tile cap of the prox grids
 
 
 def _prox_tail(v, mu, l1: float, clip: float):
@@ -48,35 +47,17 @@ def _kernel(zt_ref, ws_ref, rs_ref, z_ref, *, gamma: float, l1: float,
     z_ref[...] = v.astype(z_ref.dtype)
 
 
-def _pick_blk_d(d: int, tuned: Optional[int] = None) -> int:
-    """Lane tile for the prox grids (d % 128 == 0 — lane-aligned layout
-    rows; raises otherwise). A cached autotuner winner ``tuned`` is used
-    verbatim when it is a lane multiple dividing d."""
-    if d % LANE != 0:
-        raise ValueError(
-            f"prox lane tile requires d % {LANE} == 0, got d={d}; build "
-            f"the block table through a lane-aligned layout "
-            f"(core.blocks.make_flat_blocks / make_block_layout).")
-    if tuned is not None and tuned % LANE == 0 and 0 < tuned <= d \
-            and d % tuned == 0:
-        return tuned
-    blk_d = min(d, 512)
-    while d % blk_d:
-        blk_d //= 2
-    return blk_d
-
-
 def prox_consensus_2d(z_tilde, w_sum, rho_sum, gamma: float, l1: float,
-                      clip: float, *, interpret: bool = True,
+                      clip: float, *, interpret: Optional[bool] = None,
                       blk_m: Optional[int] = None,
                       blk_d: Optional[int] = None):
     """z_tilde, w_sum: (M, d) with d % 128 == 0 (lane-aligned rows; the
-    M grid tiles at the largest divisor of M <= 8, never padded);
+    M grid tiles at 8 rows when 8 divides M, else at M, never padded);
     rho_sum: (M, 1); blk_m/blk_d optionally override the grid tiles
     (autotuner winners). Returns z_new (M, d)."""
     M, d = z_tilde.shape
     blk_m = pick_blk_m(M, tuned=blk_m)
-    blk_d = _pick_blk_d(d, tuned=blk_d)
+    blk_d = pick_lane_tile(d, BLK_D, tuned=blk_d, rows=blk_m)
     grid = (M // blk_m, d // blk_d)
     spec = pl.BlockSpec((blk_m, blk_d), lambda i, j: (i, j))
     rs_spec = pl.BlockSpec((blk_m, 1), lambda i, j: (i, 0))
@@ -87,7 +68,7 @@ def prox_consensus_2d(z_tilde, w_sum, rho_sum, gamma: float, l1: float,
         in_specs=[spec, spec, rs_spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(z_tilde.shape, z_tilde.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(z_tilde, w_sum, rho_sum)
 
 
@@ -115,13 +96,14 @@ def _fused_kernel(z_ref, rs_ref, e_ref, w_ref, out_ref, acc_ref, *,
 
 
 def server_prox_fused_2d(z_cur, w_cache, edge_mask, rho_sum, gamma: float,
-                         l1: float, clip: float, *, interpret: bool = True,
+                         l1: float, clip: float, *,
+                         interpret: Optional[bool] = None,
                          blk_m: Optional[int] = None,
                          blk_d: Optional[int] = None):
     """Eq. (13) with the worker reduction fused into the grid.
 
     z_cur   : (M, d), d % 128 == 0 (lane-aligned rows; the M grid tiles
-        at the largest divisor of M <= 8 — M=1 PS commits included);
+        at 8 rows when 8 divides M, else at M — M=1 PS commits included);
     w_cache : (N, M, d) stale-w cache across all workers;
     edge_mask: (N, M, 1) float — 1.0 where (i, j) in E, else 0.0;
     rho_sum : (M, 1) per-block sum of rho_i over the neighborhood;
@@ -137,7 +119,7 @@ def server_prox_fused_2d(z_cur, w_cache, edge_mask, rho_sum, gamma: float,
     N, M, d = w_cache.shape
     assert z_cur.shape == (M, d), (N, M, d)
     blk_m = pick_blk_m(M, tuned=blk_m)
-    blk_d = _pick_blk_d(d, tuned=blk_d)
+    blk_d = pick_lane_tile(d, BLK_D, tuned=blk_d, rows=blk_m)
     grid = (M // blk_m, d // blk_d, N)
     spec = pl.BlockSpec((blk_m, blk_d), lambda i, j, n: (i, j))
     rs_spec = pl.BlockSpec((blk_m, 1), lambda i, j, n: (i, 0))
@@ -151,5 +133,5 @@ def server_prox_fused_2d(z_cur, w_cache, edge_mask, rho_sum, gamma: float,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(z_cur.shape, z_cur.dtype),
         scratch_shapes=[pltpu.VMEM((blk_m, blk_d), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(z_cur, rho_sum, edge_mask, w_cache)

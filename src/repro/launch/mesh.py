@@ -17,6 +17,16 @@ Production target: TPU v5e, 256 chips/pod (16x16), optionally 2 pods.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with Auto axis types: the epoch and the dryrun
+    place data with explicit ``NamedSharding``s and ``shard_map``, and
+    leave every other propagation to the compiler (JAX's default
+    Explicit axes reject the implicit gathers that relies on)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,17 +39,18 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices (set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count=512 before importing jax); have {len(devs)}")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return _auto_mesh(shape, axes, devs[:n])
 
 
 def make_test_mesh(devices: int = 8, model: int = 2):
-    """Small (data, model) host-device mesh for CPU integration tests.
+    """Small (data, model) mesh over the first ``devices`` devices: the
+    chips of one TPU host (``chip_smoke.py --chips 4``) or forced host
+    CPU devices in integration tests.
 
-    Requires the test process to have forced enough host devices
-    (``XLA_FLAGS=--xla_force_host_platform_device_count=<devices>``
-    before jax is first imported) and ``devices`` to split evenly into
-    ``model`` columns — both are validated eagerly so a bad count fails
-    with an actionable message instead of an opaque reshape error."""
+    ``devices`` must split evenly into ``model`` columns and the process
+    must see at least ``devices`` devices — both are validated eagerly
+    so a bad count fails with an actionable message instead of an
+    opaque reshape error."""
     if model <= 0 or devices <= 0:
         raise ValueError(f"devices={devices} and model={model} must be >= 1")
     if devices % model != 0:
@@ -47,13 +58,16 @@ def make_test_mesh(devices: int = 8, model: int = 2):
             f"make_test_mesh: devices={devices} does not divide into "
             f"model={model} columns (devices % model == {devices % model}); "
             f"pick devices as a multiple of the model axis")
-    have = len(jax.devices())
-    if have < devices:
+    devs = jax.devices()
+    if len(devs) < devices:
         raise RuntimeError(
-            f"make_test_mesh: need {devices} devices but jax sees {have}; "
-            f"set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{devices} before importing jax")
-    return jax.make_mesh((devices // model, model), ("data", "model"))
+            f"make_test_mesh: need {devices} devices but jax sees "
+            f"{len(devs)} ({devs[0].platform}); run on a host with that "
+            f"many chips, or on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={devices} before "
+            f"importing jax")
+    return _auto_mesh((devices // model, model), ("data", "model"),
+                      devs[:devices])
 
 
 MESH_PRESETS = ("none", "test", "pod", "multipod")

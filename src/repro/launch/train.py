@@ -25,6 +25,7 @@ from ..data import TokenPipeline
 from ..models import build_model
 from ..optim import adamw, warmup_cosine
 from ..training import SGDTrainer
+from .compile_cache import enable_compile_cache
 from .mesh import MESH_PRESETS
 
 
@@ -255,6 +256,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.runtime == "ps" and args.trainer != "admm":
         raise SystemExit("--runtime ps is the AsyBADMM Parameter Server "
                          "runtime; use --trainer admm")

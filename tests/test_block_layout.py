@@ -151,7 +151,8 @@ def test_roundtrip_at_lane_boundary_bitwise():
         for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
             assert a.dtype == b.dtype
             np.testing.assert_array_equal(
-                np.asarray(a).view(np.uint8), np.asarray(b).view(np.uint8))
+                np.asarray(a).reshape(-1).view(np.uint8),
+                np.asarray(b).reshape(-1).view(np.uint8))
         np.testing.assert_array_equal(
             np.asarray(packed)[~layout.padding_mask()], 0.0)
 
@@ -189,7 +190,7 @@ def test_sharded_divisibility_of_lane_rounded_layout():
     block counts still fail eagerly with the num_blocks message."""
     from jax.sharding import AbstractMesh
 
-    mesh = AbstractMesh((("data", 4), ("model", 2)))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     params = {"w": jnp.zeros((300,), jnp.float32)}
     cfg = ADMMConfig(rho=1.0, gamma=0.1, num_blocks=4, seed=0)
 
@@ -274,7 +275,7 @@ try:
         prefix = tuple(data.draw(st.integers(1, 3)) for _ in range(lead))
         tree = {}
         for k, (shape, dt) in enumerate(leaves):
-            vals = r.randn(*(prefix + tuple(shape))).astype(np.float32)
+            vals = np.asarray(r.randn(*(prefix + tuple(shape))), np.float32)
             tree[f"l{k}"] = jnp.asarray(vals).astype(dt)
         template = {k: jax.ShapeDtypeStruct(v.shape[lead:], v.dtype)
                     for k, v in tree.items()}
@@ -286,7 +287,8 @@ try:
         for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
             assert a.dtype == b.dtype
             np.testing.assert_array_equal(
-                np.asarray(a).view(np.uint8), np.asarray(b).view(np.uint8))
+                np.asarray(a).reshape(-1).view(np.uint8),
+                np.asarray(b).reshape(-1).view(np.uint8))
         # padding is exactly zero at every batch index
         mask = layout.padding_mask()
         np.testing.assert_array_equal(np.asarray(packed)[..., ~mask], 0.0)

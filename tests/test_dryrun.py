@@ -15,7 +15,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run(code: str, timeout=600):
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     return subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=timeout)

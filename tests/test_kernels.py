@@ -188,7 +188,11 @@ def test_pick_lane_tile_contract():
     """The lane-tile picker: actionable error off the lane grid, tuned
     winners consulted verbatim only when they are lane multiples
     dividing d, heuristic fallback otherwise."""
-    from repro.kernels.admm_update import _pick_lane_tile, pick_blk_m
+    from repro.kernels.admm_update import BLK_D
+    from repro.kernels.tiling import pick_blk_m, pick_lane_tile
+
+    def _pick_lane_tile(d, tuned=None, rows=8):
+        return pick_lane_tile(d, BLK_D, tuned=tuned, rows=rows)
 
     with pytest.raises(ValueError, match="d % 128 == 0, got d=136"):
         _pick_lane_tile(136)
@@ -197,7 +201,13 @@ def test_pick_lane_tile_contract():
     assert _pick_lane_tile(4096, tuned=512) == 512    # tuned divides -> used
     assert _pick_lane_tile(4096, tuned=384) == 2048   # tuned !divides -> fallback
     assert _pick_lane_tile(4096, tuned=100) == 2048   # tuned !lane-mult -> fallback
-    assert pick_blk_m(12, tuned=6) == 6
+    assert _pick_lane_tile(4096, rows=1) == 2048      # < 8 rows fill 8 sublanes
+    assert _pick_lane_tile(4096, rows=12) == 1024     # taller tile, narrower
+    # sublane tile: a multiple of 8 dividing M, or M itself (Mosaic's rule)
+    assert pick_blk_m(64) == 8 and pick_blk_m(12) == 12 and pick_blk_m(1) == 1
+    assert pick_blk_m(64, tuned=16) == 16
+    assert pick_blk_m(12, tuned=12) == 12
+    assert pick_blk_m(12, tuned=6) == pick_blk_m(12)  # chip-refused ignored
     assert pick_blk_m(12, tuned=5) == pick_blk_m(12)  # non-divisor ignored
 
 

@@ -16,7 +16,8 @@ from repro.launch.mesh import (MESH_PRESETS, data_axes, make_production_mesh,
 
 
 def _amesh(*shape_tuple):
-    return AbstractMesh(tuple(shape_tuple))
+    names, sizes = zip(*shape_tuple)
+    return AbstractMesh(sizes, names)
 
 
 def test_helpers_single_pod():

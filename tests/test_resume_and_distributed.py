@@ -69,6 +69,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ADMMConfig
 from repro.core import init_state, make_problem, make_step_fn, run
 from repro.data import make_sparse_logreg
+from repro.launch.mesh import make_test_mesh
 
 data = make_sparse_logreg(num_workers=4, samples_per_worker=32, dim=64,
                           density=0.2, seed=0)
@@ -84,7 +85,7 @@ cfg = ADMMConfig(rho=2.0, gamma=0.1, max_delay=1, block_fraction=0.5,
 state_ref, hist_ref = run(prob, cfg, 30, eval_every=30)
 
 # SPMD: worker axis over 'data', blocks over 'model'
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+mesh = make_test_mesh(devices=4, model=2)
 with mesh:
     state = init_state(prob, cfg)
     shard = lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec))
@@ -102,7 +103,7 @@ print('REF', hist_ref[-1]['objective'], 'SPMD', obj)
 assert abs(obj - hist_ref[-1]['objective']) < 1e-3, (obj, hist_ref)
 print('SPMD_OK')
 """
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=600)
