@@ -6,19 +6,17 @@ Prints ``name,us_per_call,derived`` CSV rows:
   convergence    — paper Fig. 2 (objective vs epoch, sync + delays)
   speedup        — paper Table 1 (event-driven coordination scalability)
   kernels        — fused-kernel HBM-traffic roofline projections
-  roofline       — §Roofline table from the dry-run artifacts
 """
 import argparse
 import sys
 import traceback
 
-from . import convergence, kernels_bench, roofline_bench, speedup
+from . import convergence, kernels_bench, speedup
 
 SUITES = {
     "convergence": convergence.main,
     "speedup": speedup.main,
     "kernels": kernels_bench.main,
-    "roofline": roofline_bench.main,
 }
 
 

@@ -42,6 +42,7 @@ from .core.metrics import kkt_violations, stationarity
 from .core.space import (ConsensusSpec, ConsensusState, TreeSpace,
                          asybadmm_epoch, consensus_residual,
                          init_consensus_state, make_spec)
+from .obs.profile import install_compile_marker
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,6 +63,10 @@ class ConsensusSession:
     data: Any = None
     z0: Any = None
     problem: Optional[ConsensusProblem] = None
+
+    def __post_init__(self):
+        # a compile inside a traced window shows on the trace, named
+        install_compile_marker()
 
     # ------------------------------------------------------------------
     # builders
@@ -150,9 +155,13 @@ class ConsensusSession:
         return asybadmm_epoch(self.spec, state, data)
 
     def step_fn(self):
-        """Jitted (state, batch) -> (state, info)."""
+        """Jitted (state, batch) -> (state, info); its programs are named
+        ``asybadmm_step`` in HLO and traces."""
         spec = self.spec
-        return jax.jit(lambda s, b: asybadmm_epoch(spec, s, b))
+
+        def asybadmm_step(state, batch):
+            return asybadmm_epoch(spec, state, batch)
+        return jax.jit(asybadmm_step)
 
     def run_ps(self, num_rounds: int, z0: Any = None, *,
                discipline: str = "lockfree",

@@ -50,6 +50,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..launch.mesh import data_axes, model_axis_size, num_workers
+from ..obs.profile import stage
 from .async_sim import minibatch_rows, validate_minibatch_data
 from .space import (ConsensusSpec, ConsensusState, SelectorContext,
                     epoch_keys, participation_mask_for, sample_delay_model)
@@ -164,7 +165,8 @@ def consensus_data_shardings(spec: ConsensusSpec, data):
 # ---------------------------------------------------------------------------
 
 class _MeshCollectives:
-    """The real thing: axis-index slicing, all_gather, psum."""
+    """The real thing: axis-index slicing, all_gather, psum. Every
+    collective opens the ``asybadmm.exchange`` scope."""
 
     def __init__(self, mesh, daxes):
         self.mesh, self.daxes = mesh, daxes
@@ -179,17 +181,21 @@ class _MeshCollectives:
         return lax.axis_index("model")
 
     def all_gather_model(self, x, axis):
-        return lax.all_gather(x, "model", axis=axis, tiled=True)
+        with stage("asybadmm.exchange"):
+            return lax.all_gather(x, "model", axis=axis, tiled=True)
 
     def all_to_all_model(self, x, split_axis, concat_axis):
-        return lax.all_to_all(x, "model", split_axis, concat_axis,
-                              tiled=True)
+        with stage("asybadmm.exchange"):
+            return lax.all_to_all(x, "model", split_axis, concat_axis,
+                                  tiled=True)
 
     def all_gather_data(self, x):
-        return lax.all_gather(x, self.daxes, axis=0, tiled=True)
+        with stage("asybadmm.exchange"):
+            return lax.all_gather(x, self.daxes, axis=0, tiled=True)
 
     def psum_data(self, x):
-        return lax.psum(x, self.daxes)
+        with stage("asybadmm.exchange"):
+            return lax.psum(x, self.daxes)
 
 
 class _SimCollectives:
@@ -266,8 +272,9 @@ def _epoch_body(spec: ConsensusSpec, space_l, coll, Nl: int, Ml: int,
         return lax.dynamic_slice_in_dim(a, mi * Ml, Ml, axis)
 
     # --- stale pull: FULL (N, M) replicated draw, sliced to the shard ---
-    delays = sample_delay_model(spec.delay_model, r_delay, N, M, state.t)
-    z_tilde = space_l.gather(state.z_hist, cols(rows(delays)))
+    with stage("asybadmm.gather"):
+        delays = sample_delay_model(spec.delay_model, r_delay, N, M, state.t)
+        z_tilde = space_l.gather(state.z_hist, cols(rows(delays)))
 
     # --- minibatch draw, like delay/selection: FULL (N, S) replicated,
     #     sliced to the local worker rows (== the single-device draw).
@@ -278,9 +285,11 @@ def _epoch_body(spec: ConsensusSpec, space_l, coll, Nl: int, Ml: int,
         shape = validate_minibatch_data(data)
         if shape is not None:              # leafless data: no-op, like
             S = shape[1]                   # subsample_worker_data
-            idx_l = take(rows(minibatch_rows(r_batch, N, S, spec.minibatch)))
-            data = jax.tree.map(
-                lambda a: a[jnp.arange(Ng)[:, None], idx_l], data)
+            with stage("asybadmm.minibatch"):
+                idx_l = take(rows(minibatch_rows(r_batch, N, S,
+                                                 spec.minibatch)))
+                data = jax.tree.map(
+                    lambda a: a[jnp.arange(Ng)[:, None], idx_l], data)
 
     # --- grads need every block of z~ for the local workers (the loss
     #     reads the whole variable). The model axis is redundant during
@@ -302,7 +311,8 @@ def _epoch_body(spec: ConsensusSpec, space_l, coll, Nl: int, Ml: int,
         # the gradient exchange below.
         zt_g = coll.all_to_all_model(z_tilde, 0, 1)   # (Ng, M, dblk)
         space_g = dataclasses.replace(space_l, num_workers=Ng)
-        losses_g, g_g = space_g.worker_grads(spec.loss_fn, zt_g, data)
+        with stage("asybadmm.grad"):
+            losses_g, g_g = space_g.worker_grads(spec.loss_fn, zt_g, data)
         losses = coll.all_gather_model(losses_g, axis=0)
         g_cols = coll.all_to_all_model(g_g, 1, 0)     # (Nl, Ml, dblk)
         gnorm_fn = lambda: coll.all_gather_data(
@@ -310,46 +320,53 @@ def _epoch_body(spec: ConsensusSpec, space_l, coll, Nl: int, Ml: int,
     else:
         z_tilde_full = (coll.all_gather_model(z_tilde, axis=1)
                         if split_model else z_tilde)
-        losses, g = space_l.worker_grads(spec.loss_fn, z_tilde_full, data)
-        g_cols = cols(g)
+        with stage("asybadmm.grad"):
+            losses, g = space_l.worker_grads(spec.loss_fn, z_tilde_full,
+                                             data)
+            g_cols = cols(g)
         gnorm_fn = lambda: coll.all_gather_data(space_l.grad_sqnorm(g))
 
     # --- selection at FULL (N, M), replicated — identical to the
     #     single-device draw (Gauss-Southwell additionally gathers the
     #     per-block grad norms over the data axes) ---
-    ctx = SelectorContext(
-        rng=r_sel, edge=edge, t=state.t,
-        block_fraction=spec.block_fraction,
-        grad_sqnorm=gnorm_fn)
-    sel = spec.selector(ctx)
+    with stage("asybadmm.select"):
+        ctx = SelectorContext(
+            rng=r_sel, edge=edge, t=state.t,
+            block_fraction=spec.block_fraction,
+            grad_sqnorm=gnorm_fn)
+        sel = spec.selector(ctx)
 
-    # --- partial participation (chaos replay): same full-(N, 1) mask
-    #     the single-device epoch ANDs in, applied before slicing so the
-    #     local tile sees the identical selection ---
-    pmask = participation_mask_for(spec.delay_model, state.t)
-    if pmask is not None:
-        sel = sel & pmask
+        # --- partial participation (chaos replay): same full-(N, 1)
+        #     mask the single-device epoch ANDs in, applied before
+        #     slicing so the local tile sees the identical selection ---
+        pmask = participation_mask_for(spec.delay_model, state.t)
+        if pmask is not None:
+            sel = sel & pmask
 
     # --- worker update (11)(12)(9) + select writes on the local tile ---
-    y, w_cache, x = space_l.worker_select_update(
-        g_cols, state.y, z_tilde, state.w_cache, state.x,
-        cols(rows(sel)), rows(rho_vec), spec.track_x)
+    with stage("asybadmm.worker_update"):
+        y, w_cache, x = space_l.worker_select_update(
+            g_cols, state.y, z_tilde, state.w_cache, state.x,
+            cols(rows(sel)), rows(rho_vec), spec.track_x)
 
     # --- the paper's w push: partial edge-masked reduce over the LOCAL
     #     workers, then one psum over data that lands in this block
     #     server's shard — w_sum never exists unsharded ---
-    w_sum = coll.psum_data(space_l.reduce_workers(w_cache, cols(rows(edge))))
-    rho_sum = cols(jnp.sum(jnp.where(edge, rho_vec[:, None], 0.0), axis=0),
-                   axis=0)
-    z_new = space_l.server_prox(space_l.current(state.z_hist), w_sum,
-                                rho_sum, spec.gamma, spec.reg)
+    with stage("asybadmm.server_update"):
+        w_sum = coll.psum_data(space_l.reduce_workers(w_cache,
+                                                      cols(rows(edge))))
+        rho_sum = cols(jnp.sum(jnp.where(edge, rho_vec[:, None], 0.0),
+                               axis=0), axis=0)
+        z_new = space_l.server_prox(space_l.current(state.z_hist), w_sum,
+                                    rho_sum, spec.gamma, spec.reg)
 
     loss = coll.psum_data(jnp.sum(losses)) / N
     info = {"loss": loss,
             "selected_fraction": jnp.mean(sel.astype(jnp.float32))}
-    new_state = ConsensusState(
-        z_hist=space_l.push(state.z_hist, z_new), y=y, w_cache=w_cache,
-        x=x, t=state.t + 1, rng=rng)
+    with stage("asybadmm.push"):
+        z_hist = space_l.push(state.z_hist, z_new)
+    new_state = ConsensusState(z_hist=z_hist, y=y, w_cache=w_cache, x=x,
+                               t=state.t + 1, rng=rng)
     return new_state, info
 
 

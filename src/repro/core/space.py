@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kernel_ops
+from ..obs.profile import stage
 from .admm import server_update, worker_update
 from .async_sim import (gather_delayed, push_history, sample_delays,
                         select_blocks, subsample_worker_data)
@@ -503,13 +504,16 @@ class _PackedOps:
 
     # ---- worker side ----------------------------------------------------
     def worker_grads(self, loss_fn, z_tilde, data, minibatch=None, rng=None):
-        data = subsample_worker_data(rng, data, minibatch)
+        with stage("asybadmm.minibatch"):
+            data = subsample_worker_data(rng, data, minibatch)
 
         def vg(zb, di):
-            zv = self.packer.from_blocks(zb)
+            with stage("asybadmm.pack"):
+                zv = self.packer.from_blocks(zb)
             return jax.value_and_grad(loss_fn)(zv, di)
         losses, g = jax.vmap(vg)(z_tilde, data)
-        return losses, self.packer.to_blocks(g)
+        with stage("asybadmm.pack"):
+            return losses, self.packer.to_blocks(g)
 
     def grad_sqnorm(self, g):
         return jnp.sum(jnp.square(g), axis=-1)
@@ -856,41 +860,46 @@ def asybadmm_epoch(spec: ConsensusSpec, state: ConsensusState, data
     rng, r_delay, r_sel, r_batch = epoch_keys(state.rng, spec.minibatch)
 
     # --- each worker pulls (possibly stale) z~ per block (Assumption 3) ---
-    delays = sample_delay_model(spec.delay_model, r_delay, N, M, state.t)
-    z_tilde = space.gather(state.z_hist, delays)
+    with stage("asybadmm.gather"):
+        delays = sample_delay_model(spec.delay_model, r_delay, N, M, state.t)
+        z_tilde = space.gather(state.z_hist, delays)
 
     # --- local gradients at z~ (eq. 5 linearization point), optionally on
     #     a fresh per-worker minibatch (incremental workers, Hong 2014) ---
-    losses, g = space.worker_grads(spec.loss_fn, z_tilde, data,
-                                   minibatch=spec.minibatch, rng=r_batch)
+    with stage("asybadmm.grad"):
+        losses, g = space.worker_grads(spec.loss_fn, z_tilde, data,
+                                       minibatch=spec.minibatch, rng=r_batch)
 
     # --- block selection (Alg. 1 line 4) via the shared policy registry ---
-    ctx = SelectorContext(rng=r_sel, edge=spec.edge, t=state.t,
-                          block_fraction=spec.block_fraction,
-                          grad_sqnorm=lambda: space.grad_sqnorm(g))
-    sel = spec.selector(ctx)
+    with stage("asybadmm.select"):
+        ctx = SelectorContext(rng=r_sel, edge=spec.edge, t=state.t,
+                              block_fraction=spec.block_fraction,
+                              grad_sqnorm=lambda: space.grad_sqnorm(g))
+        sel = spec.selector(ctx)
 
-    # --- partial participation (elastic/chaos replay): absent workers
-    #     contribute no edge updates this round — their y/w_cache/x rows
-    #     and server-cache contributions stay frozen, matching what a
-    #     crashed worker leaves behind on the block servers ---
-    pmask = participation_mask_for(spec.delay_model, state.t)
-    if pmask is not None:
-        sel = sel & pmask
+        # --- partial participation (elastic/chaos replay): absent workers
+        #     contribute no edge updates this round — their y/w_cache/x
+        #     rows and server-cache contributions stay frozen, matching
+        #     what a crashed worker leaves behind on the block servers ---
+        pmask = participation_mask_for(spec.delay_model, state.t)
+        if pmask is not None:
+            sel = sel & pmask
 
     # --- worker update (11)(12)(9) + the sel-masked merges, one fused
     #     pass over the worker bundles on the pallas backend ---
-    y, w_cache, x = space.worker_select_update(
-        g, state.y, z_tilde, state.w_cache, state.x, sel, spec.rho_vec,
-        spec.track_x)
+    with stage("asybadmm.worker_update"):
+        y, w_cache, x = space.worker_select_update(
+            g, state.y, z_tilde, state.w_cache, state.x, sel, spec.rho_vec,
+            spec.track_x)
 
     # --- server update (13): fresh w for pushers, stale cache otherwise;
     #     pallas fuses the edge-masked reduce into the prox grid ---
-    rho_sum = jnp.sum(jnp.where(spec.edge, spec.rho_vec[:, None], 0.0),
-                      axis=0)                                       # (M,)
-    z_new = space.server_consensus_update(
-        space.current(state.z_hist), w_cache, spec.edge, rho_sum,
-        spec.gamma, spec.reg)
+    with stage("asybadmm.server_update"):
+        rho_sum = jnp.sum(jnp.where(spec.edge, spec.rho_vec[:, None], 0.0),
+                          axis=0)                                   # (M,)
+        z_new = space.server_consensus_update(
+            space.current(state.z_hist), w_cache, spec.edge, rho_sum,
+            spec.gamma, spec.reg)
 
     if _EPOCH_CHECK_FINITE:
         bad = ~jnp.all(jnp.isfinite(z_new.reshape(z_new.shape[0], -1)),
@@ -899,8 +908,10 @@ def asybadmm_epoch(spec: ConsensusSpec, state: ConsensusState, data
 
     info = {"loss": jnp.mean(losses),
             "selected_fraction": jnp.mean(sel.astype(jnp.float32))}
-    return ConsensusState(z_hist=space.push(state.z_hist, z_new), y=y,
-                          w_cache=w_cache, x=x, t=state.t + 1, rng=rng), info
+    with stage("asybadmm.push"):
+        z_hist = space.push(state.z_hist, z_new)
+    return ConsensusState(z_hist=z_hist, y=y, w_cache=w_cache, x=x,
+                          t=state.t + 1, rng=rng), info
 
 
 def consensus_residual(spec: ConsensusSpec, state: ConsensusState) -> jax.Array:

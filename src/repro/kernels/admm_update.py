@@ -71,6 +71,7 @@ def admm_worker_update_2d(g, y, z_tilde, rho, *,
         out_specs=[spec, spec, spec],
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
+        name="admm_worker_update",
     )(rho, g, y, z_tilde)
 
 
@@ -139,4 +140,5 @@ def admm_worker_select_update_3d(g, y, z_tilde, w_old, sel_mask, rho,
         out_specs=[tspec] * n_out,
         out_shape=[jax.ShapeDtypeStruct(g.shape, g.dtype)] * n_out,
         interpret=resolve_interpret(interpret),
+        name="admm_worker_select_update",
     )(*operands)

@@ -69,6 +69,7 @@ def prox_consensus_2d(z_tilde, w_sum, rho_sum, gamma: float, l1: float,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(z_tilde.shape, z_tilde.dtype),
         interpret=resolve_interpret(interpret),
+        name="prox_consensus",
     )(z_tilde, w_sum, rho_sum)
 
 
@@ -134,4 +135,5 @@ def server_prox_fused_2d(z_cur, w_cache, edge_mask, rho_sum, gamma: float,
         out_shape=jax.ShapeDtypeStruct(z_cur.shape, z_cur.dtype),
         scratch_shapes=[pltpu.VMEM((blk_m, blk_d), jnp.float32)],
         interpret=resolve_interpret(interpret),
+        name="server_prox_update",
     )(z_cur, rho_sum, edge_mask, w_cache)

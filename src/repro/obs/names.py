@@ -10,6 +10,11 @@ Every name the telemetry subsystem emits is declared here:
   (``add_transport`` validates the same way);
 * ``SPAN_NAMES`` — the span/instant vocabulary of the Chrome-trace
   export (``obs/spans.py`` refuses unknown names);
+* ``DEVICE_SCOPES`` / ``HOST_SPANS`` — the ``jax.named_scope`` names
+  of the epoch's stages and the ``TraceAnnotation`` names of the PS
+  host path (``obs/profile.py`` refuses unknown names). The benchmark's
+  trace reduction reads them by name, so they are a public contract
+  like ``METRICS``;
 * ``METRICS`` — the stable metric names of the registry
   (``obs/metrics.py`` refuses unregistered spellings), with units and
   one-line descriptions. These names ARE the public contract
@@ -112,6 +117,46 @@ SPAN_NAMES: Mapping[str, tuple] = {
     "queue_depth":   ("counter",  "unprocessed pushes per lock domain"),
     "events":        ("counter",  "scheduler events processed"),
 }
+
+
+# ---------------------------------------------------------------------------
+# profiler names (obs/profile.py validates against these)
+# ---------------------------------------------------------------------------
+
+#: The ``jax.named_scope`` names of the epoch's stages (Algorithm 1).
+#: They land in the compiled program's ``op_name`` metadata, so a
+#: profiler trace attributes each device instruction to its stage.
+DEVICE_SCOPES = frozenset({
+    "asybadmm.gather",         # delay draw + ring read of z~
+    "asybadmm.minibatch",      # per-worker uniform row draw + row gather
+    "asybadmm.grad",           # worker gradients (the user's loss inside)
+    "asybadmm.pack",           # from_blocks / to_blocks (+ transposes)
+    "asybadmm.select",         # block selection + participation mask
+    "asybadmm.worker_update",  # worker update (11)(12)(9) + merges
+    "asybadmm.server_update",  # reduce + prox (13), rho_sum
+    "asybadmm.push",           # ring push of the new z
+    "asybadmm.exchange",       # mesh collectives (all_to_all, psum, ...)
+})
+
+#: The ``jax.profiler.TraceAnnotation`` names of the PS runtime's host
+#: path, plus the compile marker. They land on the profiler's host
+#: plane, on the same clock as the device planes.
+HOST_SPANS = frozenset({
+    "ps.setup",           # PSRuntime.run's set-up before the first event
+    "ps.event",           # one scheduler event's handler, end to end
+    "ps.worker.request",  # issuing a round's pulls (the enforcer serves)
+    "ps.worker.pull",     # join the pulled blocks + embed z~
+    "ps.worker.data",     # the round's (eager) minibatch draw
+    "ps.worker.grads",    # dispatch of the gradient op
+    "ps.worker.update",   # dispatch of the worker update op
+    "ps.worker.push",     # slicing the pushed w values + declares
+    "ps.sync.loss",       # blocking device->host read of a loss
+    "ps.sync.select",     # blocking device->host read of a selection
+    "ps.server.fold",     # apply_push loop of one commit
+    "ps.server.commit",   # commit_block loop of one commit
+    "ps.round_record",    # the per-round telemetry record
+    "asybadmm.compile",   # zero-length marker: a backend compile ended
+})
 
 
 # ---------------------------------------------------------------------------

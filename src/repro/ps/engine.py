@@ -43,6 +43,7 @@ import numpy as np
 from ..core.space import (BLOCK_SELECTORS, ConsensusSpec, SelectorContext,
                           epoch_keys)
 from ..core.async_sim import subsample_worker_data
+from ..obs.profile import host_span, stage
 
 
 class SpaceEngine:
@@ -114,8 +115,9 @@ class SpaceEngine:
         buf = jnp.zeros((self.N, self.M), jnp.float32)
         if gnorm_row is not None:
             buf = buf.at[i].set(jnp.asarray(gnorm_row, jnp.float32))
-        return np.asarray(fn(self.keys(t)[1], jnp.asarray(t, jnp.int32),
-                             buf))
+        out = fn(self.keys(t)[1], jnp.asarray(t, jnp.int32), buf)
+        with host_span("ps.sync.select", round=t):
+            return np.asarray(out)
 
     def _build_sel(self):
         spec = self.spec
@@ -124,7 +126,8 @@ class SpaceEngine:
             ctx = SelectorContext(rng=key, edge=spec.edge, t=t,
                                   block_fraction=spec.block_fraction,
                                   grad_sqnorm=lambda: gnorm_buf)
-            return spec.selector(ctx)
+            with stage("asybadmm.select"):
+                return spec.selector(ctx)
         return jax.jit(sel_fn)
 
     def round_data(self, t: int, data):
@@ -175,9 +178,11 @@ class SpaceEngine:
         N = self.N
 
         def embed(z_row, i):
-            return jax.tree.map(
-                lambda zl: jnp.zeros((N,) + zl.shape, zl.dtype).at[i].set(zl),
-                z_row)
+            with stage("asybadmm.gather"):
+                return jax.tree.map(
+                    lambda zl: jnp.zeros((N,) + zl.shape,
+                                         zl.dtype).at[i].set(zl),
+                    z_row)
         return jax.jit(embed)
 
     def grads(self, z_buf, data):
@@ -190,8 +195,9 @@ class SpaceEngine:
         spec, space = self.spec, self.space
 
         def g(z_buf, data):
-            losses, grad = space.worker_grads(spec.loss_fn, z_buf, data)
-            return losses, grad, space.grad_sqnorm(grad)
+            with stage("asybadmm.grad"):
+                losses, grad = space.worker_grads(spec.loss_fn, z_buf, data)
+                return losses, grad, space.grad_sqnorm(grad)
         return jax.jit(g)
 
     def update(self, i: int, g_buf, zt_buf, y, w, x, sel_row):
@@ -207,12 +213,14 @@ class SpaceEngine:
         spec, space = self.spec, self.space
 
         def upd(g_buf, zt_buf, y, w, x, sel_buf, i):
-            y2, w2, x2 = space.worker_select_update(
-                g_buf, y, zt_buf, w, x, sel_buf, spec.rho_vec, spec.track_x)
-            merge = lambda store, out: jax.tree.map(
-                lambda s, o: s.at[i].set(o[i]), store, out)
-            return merge(y, y2), merge(w, w2), (
-                merge(x, x2) if spec.track_x else x)
+            with stage("asybadmm.worker_update"):
+                y2, w2, x2 = space.worker_select_update(
+                    g_buf, y, zt_buf, w, x, sel_buf, spec.rho_vec,
+                    spec.track_x)
+                merge = lambda store, out: jax.tree.map(
+                    lambda s, o: s.at[i].set(o[i]), store, out)
+                return merge(y, y2), merge(w, w2), (
+                    merge(x, x2) if spec.track_x else x)
         return jax.jit(upd)
 
     # ------------------------------------------------------------------
@@ -245,10 +253,11 @@ class SpaceEngine:
         spec, space = self.spec, self.space
 
         def commit(z_col, w_col, e_col, rs):
-            out = space.server_consensus_update(
-                z_col[None], w_col[:, None, :], e_col, rs,
-                spec.gamma, spec.reg)
-            return out[0]
+            with stage("asybadmm.server_update"):
+                out = space.server_consensus_update(
+                    z_col[None], w_col[:, None, :], e_col, rs,
+                    spec.gamma, spec.reg)
+                return out[0]
         return jax.jit(commit)
 
     # ------------------------------------------------------------------

@@ -38,6 +38,8 @@ from __future__ import annotations
 import heapq
 from typing import Callable, List, Optional, Tuple
 
+from ..obs.profile import host_span
+
 
 class EventScheduler:
     """Run callbacks at simulated times; ``run`` drains the queue."""
@@ -100,7 +102,8 @@ class EventScheduler:
             time, _, fn, _tag = heapq.heappop(self._q)
             self.now = time
             self.events_processed += 1
-            fn()
+            with host_span("ps.event"):
+                fn()
             if self.after_event is not None:
                 self.after_event()
             if self.observer is not None:

@@ -43,6 +43,7 @@ import numpy as np
 
 from ..core.space import ConsensusSpec
 from ..obs import MetricsRegistry, as_telemetry, hist
+from ..obs.profile import host_span, install_compile_marker
 from . import recovery as _recovery
 from .chaos import FaultInjector, FaultPlan
 from .engine import SpaceEngine
@@ -96,6 +97,7 @@ class PSRuntime:
         if compute not in ("real", "timing"):
             raise ValueError(f"compute must be 'real' or 'timing'; "
                              f"got {compute!r}")
+        install_compile_marker()
         self.spec = spec
         self.engine = SpaceEngine(spec)
         self.discipline = discipline
@@ -149,19 +151,10 @@ class PSRuntime:
                 "(random/cyclic/zipf) for timing studies)")
 
     # ------------------------------------------------------------------
-    def run(self, num_rounds: int, z0=None, *,
-            checkpoint_every: Optional[int] = None,
-            checkpoint_dir: Optional[str] = None,
-            resume_from: Optional[str] = None) -> PSRunResult:
-        """Drive ``num_rounds`` rounds. Durability knobs
-        (``ps/recovery.py``): ``checkpoint_every=E`` writes an atomic,
-        crash-consistent snapshot of the whole runtime to
-        ``checkpoint_dir`` at rounds E, 2E, ... (a quiescent barrier —
-        part of the run's schedule); ``resume_from=`` (a snapshot
-        prefix, file, or the checkpoint directory for its latest)
-        restores one and continues mid-stream, producing results
-        identical to the uninterrupted run. ``checkpoint_every=None``
-        (default) is byte-identical to the pre-durability runtime."""
+    def _launch(self, num_rounds: int, z0, checkpoint_every,
+                checkpoint_dir, resume_from) -> bool:
+        """Build one run's scheduler, trace, procs and metrics and queue
+        its first events; returns whether the run is elastic."""
         if num_rounds < 1:
             raise ValueError("num_rounds must be >= 1")
         eng = self.engine
@@ -332,7 +325,26 @@ class PSRuntime:
                 # blocks with an empty edge neighborhood still commit
                 # every round (prox-only decay, as the epoch does)
                 self.sched.at(0.0, dom._maybe_commit)
+        return elastic
+
+    def run(self, num_rounds: int, z0=None, *,
+            checkpoint_every: Optional[int] = None,
+            checkpoint_dir: Optional[str] = None,
+            resume_from: Optional[str] = None) -> PSRunResult:
+        """Drive ``num_rounds`` rounds. Durability knobs
+        (``ps/recovery.py``): ``checkpoint_every=E`` writes an atomic,
+        crash-consistent snapshot of the whole runtime to
+        ``checkpoint_dir`` at rounds E, 2E, ... (a quiescent barrier —
+        part of the run's schedule); ``resume_from=`` (a snapshot
+        prefix, file, or the checkpoint directory for its latest)
+        restores one and continues mid-stream, producing results
+        identical to the uninterrupted run. ``checkpoint_every=None``
+        (default) is byte-identical to the pre-durability runtime."""
+        with host_span("ps.setup"):
+            elastic = self._launch(num_rounds, z0, checkpoint_every,
+                                   checkpoint_dir, resume_from)
         makespan = self.sched.run()
+        eng, workers = self.engine, self._workers
 
         # --- invariants ---
         for wk in workers:
@@ -436,6 +448,11 @@ class PSRuntime:
             self.fabric.register_metrics(reg, self)
 
     def _round_record(self, version: int, now: float) -> Dict[str, Any]:
+        with host_span("ps.round_record", round=version - 1):
+            return self._build_round_record(version, now)
+
+    def _build_round_record(self, version: int, now: float
+                            ) -> Dict[str, Any]:
         """One per-round stream record (obs/stream.py schema), built
         the moment the LAST lock domain published ``version`` — pure
         reads of committed state and monotone counters (no rng, no
@@ -643,7 +660,9 @@ class PSRuntime:
             del self._data_refs[t]
 
     def record_loss(self, t: int, i: int, loss) -> None:
-        self._losses[t].append(float(loss))
+        with host_span("ps.sync.loss", round=t, worker=i):
+            value = float(loss)
+        self._losses[t].append(value)
 
     def on_worker_progress(self) -> None:
         """A worker advanced a round: without full-trajectory recording,

@@ -57,6 +57,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.profile import host_span
+
 
 # ---------------------------------------------------------------------------
 # coordination disciplines = block -> lock-domain groupings + commit mode
@@ -364,12 +366,14 @@ class BlockServerProc:
             self.wal.record_commit(v, [(i, j) for (i, j, _) in pushes])
         self.fold_log.extend((v, i, j) for (i, j, _) in pushes)
         if not self.timing_only:
-            for (i, j, value) in pushes:
-                self.caches[j] = self.engine.apply_push(self.caches[j], i,
-                                                        value)
-            for j in self.block_ids:
-                self.contents[j][v + 1] = self.engine.commit_block(
-                    j, self.contents[j][v], self.caches[j])
+            with host_span("ps.server.fold", version=v, server=self.sid):
+                for (i, j, value) in pushes:
+                    self.caches[j] = self.engine.apply_push(
+                        self.caches[j], i, value)
+            with host_span("ps.server.commit", version=v, server=self.sid):
+                for j in self.block_ids:
+                    self.contents[j][v + 1] = self.engine.commit_block(
+                        j, self.contents[j][v], self.caches[j])
             if self.rt is not None and self.rt.check_finite:
                 for j in self.block_ids:
                     if not np.all(np.isfinite(

@@ -45,6 +45,8 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs.profile import host_span
+
 
 class WorkerProc:
     def __init__(self, i: int, runtime, *, cold: bool = False):
@@ -119,6 +121,13 @@ class WorkerProc:
         self._issued = False
         self._pending = len(self.rt.domains)
         self._issue_time = self.rt.sched.now
+        with host_span("ps.worker.request", round=t, worker=self.i):
+            self._request_pulls(t)
+        self._issued = True
+        if self._pending == 0:
+            self._start_compute()
+
+    def _request_pulls(self, t: int) -> None:
         net = self.rt.net
         for dom in self.rt.domains:
             if self.rt.transport is not None:
@@ -139,9 +148,6 @@ class WorkerProc:
                         self._guarded(lambda: self._on_pull(dom, version)))
             self.rt.enforcer.request(dom, t, self.rt.sched.now, resolve,
                                      worker=self.i)
-        self._issued = True
-        if self._pending == 0:
-            self._start_compute()
 
     def _on_pull(self, dom, version: int, payload=None) -> None:
         self._pulled[dom.sid] = version
@@ -270,32 +276,38 @@ class WorkerProc:
         if rt.timing_only:
             sel_row = eng.select(t, i, None)
         else:
-            z_buf = eng.z_tilde_buffer(i, contents)
-            data = rt.data_for(t)
-            losses, g_buf, gnorm = eng.grads(z_buf, data)
-            rt.record_loss(t, i, losses[i])
+            with host_span("ps.worker.pull", round=t, worker=i):
+                z_buf = eng.z_tilde_buffer(i, contents)
+            with host_span("ps.worker.data", round=t, worker=i):
+                data = rt.data_for(t)
+            with host_span("ps.worker.grads", round=t, worker=i):
+                losses, g_buf, gnorm = eng.grads(z_buf, data)
+                loss = losses[i]
+            rt.record_loss(t, i, loss)
             sel_row = eng.select(
                 t, i, gnorm[i] if eng.needs_grads_for_select() else None)
-            rt.y, rt.w, rt.x = eng.update(
-                i, g_buf, z_buf, rt.y, rt.w, rt.x, sel_row)
+            with host_span("ps.worker.update", round=t, worker=i):
+                rt.y, rt.w, rt.x = eng.update(
+                    i, g_buf, z_buf, rt.y, rt.w, rt.x, sel_row)
         # declare to every edge domain; push fresh w where selected (the
         # declaration + its pushes travel as ONE message, so a round's
         # pushes never overtake their own declaration under latency;
         # deliveries stay valid even if the worker dies after sending —
         # the round completed, so they are NOT incarnation-guarded)
         sel_row = np.asarray(sel_row, bool) & eng.edge[i]
-        for dom in rt.domains_of_worker[i]:
-            pushes = [(j, None if rt.timing_only
-                       else eng.push_value(rt.w, i, j))
-                      for j in dom.block_ids if sel_row[j]]
-            if rt.transport is not None:
-                self._declare_reliably(dom, t, pushes)
-            elif rt.net is None:
-                dom.on_declare(i, t, pushes)
-            else:
-                rt.sched.after(rt.net.sample(self.rng),
-                               lambda dom=dom, pushes=pushes:
-                               dom.on_declare(i, t, pushes))
+        with host_span("ps.worker.push", round=t, worker=i):
+            for dom in rt.domains_of_worker[i]:
+                pushes = [(j, None if rt.timing_only
+                           else eng.push_value(rt.w, i, j))
+                          for j in dom.block_ids if sel_row[j]]
+                if rt.transport is not None:
+                    self._declare_reliably(dom, t, pushes)
+                elif rt.net is None:
+                    dom.on_declare(i, t, pushes)
+                else:
+                    rt.sched.after(rt.net.sample(self.rng),
+                                   lambda dom=dom, pushes=pushes:
+                                   dom.on_declare(i, t, pushes))
         self.rounds_done += 1
         rt.data_done(t)
         self._begin_round(t + 1)
