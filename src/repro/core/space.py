@@ -504,16 +504,30 @@ class _PackedOps:
 
     # ---- worker side ----------------------------------------------------
     def worker_grads(self, loss_fn, z_tilde, data, minibatch=None, rng=None):
+        """Each worker's loss and (M, dblk) gradient at its z~ row.
+
+        One worker at a time (``lax.map``): unpack its (M, dblk) rows to
+        the user's representation, differentiate, repack. A ``vmap``
+        here would differentiate against a batched (N, d) view, which
+        the TPU compiler writes out twice (tiled and linear) and relays
+        between them and the block table in loops over the whole
+        (N, M, dblk) table; per worker, the gather and the scatter-add
+        of the gradient read and write the unbatched vector directly."""
         with stage("asybadmm.minibatch"):
             data = subsample_worker_data(rng, data, minibatch)
 
-        def vg(zb, di):
+        def vg(args):
+            zb, di = args
             with stage("asybadmm.pack"):
                 zv = self.packer.from_blocks(zb)
-            return jax.value_and_grad(loss_fn)(zv, di)
-        losses, g = jax.vmap(vg)(z_tilde, data)
-        with stage("asybadmm.pack"):
-            return losses, self.packer.to_blocks(g)
+            # the loop body lowers to a function of its own, and XLA names
+            # the reducers inside it from there: the caller's
+            # ``asybadmm.grad`` would not reach the loss's reductions
+            with stage("asybadmm.grad"):
+                loss, g = jax.value_and_grad(loss_fn)(zv, di)
+            with stage("asybadmm.pack"):
+                return loss, self.packer.to_blocks(g)
+        return jax.lax.map(vg, (z_tilde, data))
 
     def grad_sqnorm(self, g):
         return jnp.sum(jnp.square(g), axis=-1)

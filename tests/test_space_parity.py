@@ -138,3 +138,95 @@ def test_pytree_heterogeneous_rho_changes_trajectory():
     z_heterog = final_z(RHO_SCALE)
     assert np.isfinite(z_homog).all() and np.isfinite(z_heterog).all()
     assert float(np.max(np.abs(z_homog - z_heterog))) > 1e-4
+
+
+# ---------------------------------------------------------------------------
+# worker_grads against a plain vmap of value_and_grad on the user's value
+# ---------------------------------------------------------------------------
+
+GN, GROWS, GNNZ = 4, 24, 5
+
+
+def _sparse_logreg(z, rows):
+    idx, val, y = rows
+    return jnp.mean(jax.nn.softplus(-y * jnp.sum(val * z[idx], axis=-1)))
+
+
+def _flat_case():
+    """FlatSpace whose used_dim (143) is not a multiple of the lane."""
+    from repro.core.blocks import make_flat_blocks
+    from repro.core.space import FlatSpace
+    dim = 1000
+    blocks = make_flat_blocks(dim, 7)
+    assert blocks.used_dim % 128 and blocks.dim < blocks.logical_dim
+    r = np.random.RandomState(3)
+    data = (jnp.asarray(r.randint(0, dim, (GN, GROWS, GNNZ)), jnp.int32),
+            jnp.asarray(r.randn(GN, GROWS, GNNZ), jnp.float32),
+            jnp.asarray(r.choice([-1.0, 1.0], (GN, GROWS)), jnp.float32))
+    z_user = jnp.asarray(r.randn(GN, dim), jnp.float32)
+    # the gather's transpose adds the same terms in the same order
+    # batched or not: the packed gradient is bitwise the reference's
+    return FlatSpace(blocks=blocks, num_workers=GN), _sparse_logreg, \
+        z_user, data, True
+
+
+def _tree_user(p):
+    return jnp.concatenate([leaf.reshape(-1) for leaf in jax.tree.leaves(p)])
+
+
+def _tree_logreg(p, d):
+    X, y = d
+    return jnp.mean(jax.nn.softplus(-y * (X @ _tree_user(p))))
+
+
+def _tree_case():
+    """TreeSpace over ragged leaves: blocks of unequal packed size."""
+    from repro.core.blocks import make_block_layout
+    from repro.core.space import TreeSpace
+    shapes = {"a": (3, 5), "b": (7,), "c": (2, 2, 3), "d": (1,)}
+    params = {k: jnp.zeros(s, jnp.float32) for k, s in shapes.items()}
+    layout = make_block_layout(params, num_blocks=3)
+    assert len(set(layout.block_sizes)) > 1
+    r = np.random.RandomState(4)
+    z_user = {k: jnp.asarray(r.randn(GN, *s), jnp.float32)
+              for k, s in shapes.items()}
+    n = _tree_user(params).shape[0]
+    data = (jnp.asarray(r.randn(GN, GROWS, n), jnp.float32),
+            jnp.asarray(r.choice([-1.0, 1.0], (GN, GROWS)), jnp.float32))
+    space = TreeSpace(blocks=layout.tree, num_workers=GN, layout=layout)
+    # a batched matmul reduces in another order than an unbatched one
+    return space, _tree_logreg, z_user, data, False
+
+
+@pytest.mark.parametrize("minibatch", [None, 0.5], ids=["full", "minibatch"])
+@pytest.mark.parametrize("case", [_flat_case, _tree_case],
+                         ids=["flat_unaligned", "tree_ragged"])
+def test_worker_grads_match_vmapped_reference(case, minibatch):
+    """The per-worker ``worker_grads`` gives each worker's loss and packed
+    gradient exactly as a vmapped ``value_and_grad`` on the user's value
+    does, on the same minibatch rows."""
+    from repro.core.async_sim import subsample_worker_data
+    space, loss_fn, z_user, data, bitwise_grad = case()
+    rng = jax.random.PRNGKey(11)
+    packer = space.packer
+    z_tilde = packer.to_blocks(z_user)
+
+    losses, g = jax.jit(lambda zt, d: space.worker_grads(
+        loss_fn, zt, d, minibatch=minibatch, rng=rng))(z_tilde, data)
+
+    rows = subsample_worker_data(rng, data, minibatch)
+    ref_losses, ref_g = jax.jit(jax.vmap(jax.value_and_grad(loss_fn)))(
+        z_user, rows)
+    assert losses.shape == (GN,)
+    assert g.shape == z_tilde.shape
+    ref_g = packer.to_blocks(ref_g)
+    if bitwise_grad:
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(ref_g))
+    # float32 sums may reduce in another order batched than unbatched:
+    # 1e-6 of each array's largest entry (a gradient entry that cancels
+    # to near 0 carries the rounding of the terms it summed)
+    for got, want in ((losses, ref_losses), (g, ref_g)):
+        want = np.asarray(want)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                                   atol=1e-6 * np.abs(want).max())
+    assert np.all(np.asarray(g)[:, ~packer.padding_mask()] == 0.0)

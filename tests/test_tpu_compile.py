@@ -14,7 +14,9 @@ only one process may load the TPU library, and xdist workers all
 import every test file.
 """
 import dataclasses
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -26,8 +28,9 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.api import ConsensusSession
 from repro.configs.base import ADMMConfig
+from repro.core.blocks import make_flat_blocks
 from repro.core.sharded import consensus_data_specs, consensus_state_specs
-from repro.core.space import asybadmm_epoch, init_consensus_state
+from repro.core.space import FlatSpace, asybadmm_epoch, init_consensus_state
 from repro.kernels import tiling
 from repro.kernels.admm_update import admm_worker_select_update_3d
 from repro.kernels.flash_attention import flash_attention_bhsd
@@ -173,3 +176,39 @@ def test_sharded_kdda_epoch_compiles(topo, native_kernels):
     text = c.as_text()
     assert NATIVE in text
     assert "all-reduce" in text and "all-to-all" in text
+
+
+KDDA_FEATURES, KDDA_NNZ = 20_216_830, 36        # bench kdda_l1logreg
+
+
+def _sparse_logreg(z, rows):
+    idx, val, y = rows
+    return jnp.mean(jax.nn.softplus(-y * jnp.sum(val * z[idx], axis=-1)))
+
+
+def _element_counts(hlo: str) -> set:
+    return {math.prod(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"\b[a-z]+[0-9]*\[([0-9,]*)\]", hlo)}
+
+
+@pytest.mark.parametrize("rows,temp_cap",
+                         [(1_026, 0.5e9), (1_050_969, 2e9)],
+                         ids=["minibatch", "full_batch"])
+def test_worker_grads_holds_no_batched_flat_view(one_chip, rows, temp_cap):
+    """``worker_grads`` at the KDDa table (N=8, M=64) differentiates each
+    worker against its own (d,) vector: no array of N*d (or N*M*used_dim)
+    elements, the batched view a vmap over workers writes out, and
+    temporaries of one worker's vector and rows, not N of them."""
+    N, M = 8, 64
+    space = FlatSpace(blocks=make_flat_blocks(KDDA_FEATURES, M),
+                      num_workers=N)
+    b = space.blocks
+    data = (_sds((N, rows, KDDA_NNZ), one_chip, jnp.int32),
+            _sds((N, rows, KDDA_NNZ), one_chip), _sds((N, rows), one_chip))
+    c = _compile(lambda zt, d: space.worker_grads(_sparse_logreg, zt, d),
+                 _sds((N, M, b.block_dim), one_chip), data)
+    counts = _element_counts(c.as_text())
+    assert b.dim in counts                       # the per-worker vector
+    assert not counts & {N * b.dim, N * b.logical_dim}
+    temp = c.memory_analysis().temp_size_in_bytes
+    assert temp < temp_cap, temp
